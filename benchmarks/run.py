@@ -12,9 +12,11 @@ timings + phase costs) so the perf trajectory is tracked across PRs.
 from pathlib import Path
 
 from benchmarks import bench_kernels, bench_partition, bench_pmvc, bench_roofline
+from repro.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     print("# === 1. partition quality (Tables 4.3-4.6) ===")
     rows = bench_partition.run()
     print("\n# === Table 4.7 analogue: win rates per combo ===")

@@ -27,6 +27,7 @@ import time
 import numpy as np
 
 from repro.api import EXCHANGES, SOLVERS, Topology, distribute
+from repro.compile_cache import enable_compile_cache
 from repro.configs.paper_pmvc import COMBOS
 from repro.sparse import PAPER_SUITE, generate
 
@@ -78,6 +79,7 @@ def main() -> None:
     ap.add_argument("--users", type=int, default=0,
                     help="also serve N personalized-PageRank users batched")
     args = ap.parse_args()
+    enable_compile_cache()
 
     a = generate(PAPER_SUITE[args.matrix])
     print(f"matrix {args.matrix}: N={a.shape[0]} NNZ={a.nnz} "

@@ -15,6 +15,7 @@ import time
 import numpy as np
 
 from repro.api import Topology, distribute, set_memo_limit
+from repro.compile_cache import enable_compile_cache
 from repro.serve import (
     QueueFullError,
     ServeDriver,
@@ -46,6 +47,7 @@ def main() -> None:
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-queue", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # Tenant A's session is registered live; tenant B's is registered as
     # a *saved plan path* — it hydrates from the plan store on first

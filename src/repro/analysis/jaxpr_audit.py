@@ -48,15 +48,20 @@ __all__ = [
     "trace_pmvc_step",
 ]
 
-# Primitive names folded into the schedule signature, normalized. psum
-# traces as "psum2" on current jax; both spell the same reduction.
+# The unit contraction's multiply-then-reduce
+# (:func:`repro.pmvc.dist._unit_spmm`) traces as one ``reduce_sum``.
+_CONTRACTION_PRIMITIVE = "reduce_sum"
+
+# Primitive names folded into the schedule signature, normalized. A psum
+# whose operand varies over the mesh axis traces as "psum_invariant"
+# inside shard_map; both spell the same reduction.
 _SIGNATURE_TOKENS = {
     "all_to_all": "a2a",
     "all_gather": "all_gather",
     "ppermute": "ppermute",
-    "dot_general": "dot",
+    _CONTRACTION_PRIMITIVE: "dot",
     "psum": "psum",
-    "psum2": "psum",
+    "psum_invariant": "psum",
 }
 
 # Host-callback primitives — none may appear inside a step (a silent
@@ -144,7 +149,7 @@ def audit_jaxpr(closed_jaxpr, *, expect_waves: Optional[int] = None) -> List[Fin
     * no weak-typed loop carries in ``while``/``scan`` (recompile bait:
       a python scalar in the carry retraces on first concrete call);
     * with ``expect_waves``: the overlap ordering property — every
-      ``all_to_all`` precedes the first ``dot_general``, and there are
+      ``all_to_all`` precedes the first contraction, and there are
       exactly K of them.
     """
     findings: List[Finding] = []
@@ -205,7 +210,7 @@ def audit_jaxpr(closed_jaxpr, *, expect_waves: Optional[int] = None) -> List[Fin
                     "transfer can no longer hide behind earlier FLOPs",
                 )
             )
-        elif name == "dot_general":
+        elif name == _CONTRACTION_PRIMITIVE:
             saw_dot = True
     if expect_waves is not None and a2a_before != expect_waves:
         findings.append(
@@ -223,11 +228,9 @@ def audit_jaxpr(closed_jaxpr, *, expect_waves: Optional[int] = None) -> List[Fin
 
 
 def _abstract_mesh(num_units: int):
-    # Version-agnostic shim (AbstractMesh's ctor changed across jax
-    # releases) — same one the executors use.
-    from repro.launch.mesh import make_abstract_mesh
+    from jax.sharding import AbstractMesh
 
-    return make_abstract_mesh((num_units,), ("unit",))
+    return AbstractMesh((num_units,), ("unit",))
 
 
 def trace_pmvc_step(
@@ -241,10 +244,8 @@ def trace_pmvc_step(
 
     ``exchange_plan`` follows the executor convention (``None`` ==
     replicated, :class:`SelectivePlan`, :class:`OverlapPlan`). The x
-    operand is a single vector by default (the contraction then traces
-    as ``dot_general``; the batched CPU path lowers to broadcast-sums,
-    which would hide the contraction from the schedule signature) —
-    pass ``batch`` to audit the SpMM path instead.
+    operand is a single vector by default — pass ``batch`` to audit the
+    SpMM path instead (the same contraction with a batch axis).
     """
     import jax
 

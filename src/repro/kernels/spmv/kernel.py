@@ -60,10 +60,7 @@ def _spmm_kernel(
     contrib = jnp.dot(
         tiles_ref[0], x_ref[0], preferred_element_type=jnp.float32
     )
-    cur = pl.load(acc_ref, (pl.ds(r, 1), slice(None), slice(None)))
-    pl.store(
-        acc_ref, (pl.ds(r, 1), slice(None), slice(None)), cur + contrib[None]
-    )
+    acc_ref[pl.ds(r, 1), :, :] += contrib[None]
 
     @pl.when(t == nt - 1)
     def _flush():

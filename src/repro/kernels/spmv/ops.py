@@ -1,7 +1,8 @@
 """Jitted public entry points for the BELL SpMM/SpMV kernel.
 
-``spmv_shard`` / ``spmm_shard`` run the Pallas kernel (interpret-mode on
-CPU, compiled on TPU); ``pack_inputs`` converts a host-side
+``spmv_shard`` / ``spmm_shard`` run the Pallas kernel, compiled for the
+TPU unless the caller asks for ``interpret=True`` (how it runs on a
+CPU); ``pack_inputs`` converts a host-side
 :class:`repro.sparse.bell.BellShard` plus a single ``[N]`` vector or a
 ``[B, N]`` batch into device arrays.
 """
@@ -26,10 +27,6 @@ __all__ = [
 ]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def pack_inputs(
     shard: BellShard, x: np.ndarray, bn: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
@@ -52,11 +49,9 @@ def spmv_shard(
     x_blocks: jax.Array,
     num_row_blocks: int,
     *,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """One shard's PMVC: returns the local y block ``[R, bm]``."""
-    if interpret is None:
-        interpret = not _on_tpu()
     return bell_spmv(
         tiles, tile_row, tile_col, x_blocks, num_row_blocks, interpret=interpret
     )
@@ -69,11 +64,9 @@ def spmm_shard(
     x_blocks: jax.Array,  # [NCB, bn, B]
     num_row_blocks: int,
     *,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """One shard's batched PMVC: returns the local y block ``[R, bm, B]``."""
-    if interpret is None:
-        interpret = not _on_tpu()
     return bell_spmm(
         tiles, tile_row, tile_col, x_blocks, num_row_blocks, interpret=interpret
     )

@@ -18,20 +18,8 @@ import jax
 __all__ = [
     "make_production_mesh",
     "make_test_mesh",
-    "make_abstract_mesh",
     "batch_axes_of",
 ]
-
-
-def make_abstract_mesh(shape: Tuple[int, ...], names: Tuple[str, ...]):
-    """Version-agnostic AbstractMesh: jax >= 0.5 takes (shape, names),
-    0.4.x takes ((name, size), ...) pairs."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(shape, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, shape)))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

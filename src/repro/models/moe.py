@@ -28,11 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.config import ArchConfig
 from repro.models.common import Params, dense_init
 
@@ -305,7 +300,7 @@ def moe_ffn(
             ranks=ranks,
             sort_dispatch=cfg.moe_sort_dispatch,
         )
-        y = _shard_map(
+        y = jax.shard_map(
             fn,
             mesh=ctx.mesh,
             in_specs=(
@@ -328,7 +323,7 @@ def moe_ffn(
         model_axis=ctx.model_axis,
         sort_dispatch=cfg.moe_sort_dispatch,
     )
-    y = _shard_map(
+    y = jax.shard_map(
         fn,
         mesh=ctx.mesh,
         in_specs=(

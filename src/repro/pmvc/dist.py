@@ -7,8 +7,8 @@ Phases mirror ch.4's measurement decomposition:
   total``, all-gather) or moved by the **selective exchange** — a static
   all_to_all schedule carrying only the C_Xk blocks each unit needs
   (:class:`repro.pmvc.plan_device.SelectivePlan`).
-* **Compute**: per-unit Block-ELL SpMM (Pallas kernel on TPU, jnp oracle
-  elsewhere).
+* **Compute**: per-unit Block-ELL SpMM — one jnp contraction on every
+  backend (:func:`_unit_spmm`).
 * **Gather + construction of Y**: partial y vectors summed across units
   (column fragments overlap rows — the paper's fan-in with accumulation)
   via ``psum``; row-clean plans could concat instead (cheaper — the
@@ -40,17 +40,13 @@ the production path and dry-run).
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.5
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from repro.pmvc.plan_device import (
     DevicePlan,
@@ -144,26 +140,19 @@ def _unit_spmm(
 ) -> jax.Array:
     """One unit's padded-tile SpMM into a full-length partial y.
 
-    ``xb_of_tile`` is ``[T, bn]`` (single vector) or ``[T, bn, B]``;
-    jnp formulation (oracle-equivalent); the Pallas kernel is used by the
-    per-shard benchmark path where the unit loop is explicit."""
-    if xb_of_tile.ndim == 2:
-        contribs = jnp.einsum("tmn,tn->tm", tiles, xb_of_tile)  # [T, bm]
-        y = jnp.zeros((nrb, tiles.shape[1]), jnp.float32)
-        return y.at[tile_row].add(contribs)
-    if jax.default_backend() == "cpu":
-        # Batched contraction unrolled over bn as broadcast outer products:
-        # XLA CPU fuses the chain into one vectorized loop with the batch
-        # axis innermost (~3× faster than its tiny-batched-GEMM path for
-        # einsum "tmn,tnb->tmb").
-        bn = tiles.shape[-1]
-        contribs = sum(
-            tiles[..., n, None] * xb_of_tile[..., None, n, :] for n in range(bn)
-        )  # [T, bm, B]
-    else:
-        # Accelerators get the real batched matmul (MXU/tensor cores).
-        contribs = jnp.einsum("tmn,tnb->tmb", tiles, xb_of_tile)
-    y = jnp.zeros((nrb, tiles.shape[1], xb_of_tile.shape[-1]), jnp.float32)
+    ``xb_of_tile`` is ``[T, bn]`` (single vector, run as B = 1) or
+    ``[T, bn, B]``. One lowering on every backend and executor: an f32
+    broadcast multiply summed over the *minor* (bn) axis, with the batch
+    as a major axis. Every output element is then reduced by the same
+    code whatever B is, so column j of a B-wide SpMM is bitwise the B = 1
+    product — the per-column stability that served ≡ direct rests on
+    (a batch-minor reduction or an MXU einsum breaks it on TPU, and the
+    default-precision einsum also misses the f32 accuracy bar)."""
+    squeeze = xb_of_tile.ndim == 2
+    xt = xb_of_tile[:, None] if squeeze else jnp.swapaxes(xb_of_tile, 1, 2)
+    contribs = jnp.sum(tiles[:, None] * xt[:, :, None, :], axis=-1)  # [T, B, bm]
+    contribs = contribs[:, 0] if squeeze else jnp.swapaxes(contribs, 1, 2)
+    y = jnp.zeros((nrb,) + contribs.shape[1:], jnp.float32)
     return y.at[tile_row].add(contribs)
 
 
@@ -234,76 +223,84 @@ def make_simulate_fn(
 
     Plan arrays are hoisted to device once, here — callers that keep the
     closure (the ``simulate`` executor, the ``device_loop`` solver fast
-    path) never re-pay host→device conversion per call. The closure is
-    pure JAX, so it can be jitted (``jit=True``) and traced inside
-    ``lax.fori_loop`` / ``while_loop`` solver bodies. ``transform`` is
-    the optional value-view map applied to tile payloads at hoist time
-    (see :func:`hoist_tiles`).
+    path) never re-pay host→device conversion per call. They enter the
+    jitted program as arguments, never as closed-over constants: a
+    constant is compiled into the program, which at serving sizes
+    (GBs of tiles) costs minutes of compilation and tens of GiB of host
+    memory. The closure is pure JAX, so it can be jitted (``jit=True``)
+    and traced inside ``lax.fori_loop`` / ``while_loop`` solver bodies.
+    ``transform`` is the optional value-view map applied to tile
+    payloads at hoist time (see :func:`hoist_tiles`).
     """
     nrb = plan.num_row_blocks
     if isinstance(selective, OverlapPlan):
-        return _make_simulate_overlap_fn(plan, selective, jit=jit, transform=transform)
-    tiles = hoist_tiles(plan.tiles, transform)
-    tile_row = jnp.asarray(plan.tile_row)
+        ops, body = _simulate_overlap(plan, selective, transform)
+    elif selective is None:
+        ops = (
+            hoist_tiles(plan.tiles, transform),
+            jnp.asarray(plan.tile_row),
+            jnp.asarray(plan.tile_col),
+        )
 
-    if selective is None:
-        tile_col = jnp.asarray(plan.tile_col)
-
-        def run(xb: jax.Array) -> jax.Array:
+        def body(ops, xb: jax.Array) -> jax.Array:
             def one_unit(t, r, c):
                 return _unit_spmm(t, r, xb[c], nrb)
 
-            partials = jax.vmap(one_unit)(tiles, tile_row, tile_col)
+            return jax.vmap(one_unit)(*ops).sum(axis=0)
+
+    else:
+        sp = selective
+        ops = (
+            hoist_tiles(plan.tiles, transform),
+            jnp.asarray(plan.tile_row),
+            jnp.asarray(sp.tile_col_local),
+            jnp.asarray(sp.recv_src),
+            jnp.asarray(sp.recv_lane),
+            jnp.asarray(sp.owned),  # [U, per]
+            jnp.asarray(sp.send_idx),  # [U, U, L]
+        )
+
+        def body(ops, xb: jax.Array) -> jax.Array:
+            tiles, tile_row, tile_col_local, recv_src, recv_lane, owned, send_idx = ops
+            _, recv = _emulated_exchange(owned, send_idx, xb)
+
+            def one_unit(t, r, tcl, recv_u, src, lane):
+                ws = recv_u[src, lane]  # [W, bn(, B)] compact workspace
+                return _unit_spmm(t, r, ws[tcl], nrb)
+
+            partials = jax.vmap(one_unit)(
+                tiles, tile_row, tile_col_local, recv, recv_src, recv_lane
+            )
             return partials.sum(axis=0)
 
-        return jax.jit(run) if jit else run
-
-    sp = selective
-    tile_col_local = jnp.asarray(sp.tile_col_local)
-    owned = jnp.asarray(sp.owned)  # [U, per]
-    send_idx = jnp.asarray(sp.send_idx)  # [U, U, L]
-    recv_src = jnp.asarray(sp.recv_src)
-    recv_lane = jnp.asarray(sp.recv_lane)
-
-    def run_selective(xb: jax.Array) -> jax.Array:
-        _, recv = _emulated_exchange(owned, send_idx, xb)
-
-        def one_unit(t, r, tcl, recv_u, src, lane):
-            ws = recv_u[src, lane]  # [W, bn(, B)] compact workspace
-            return _unit_spmm(t, r, ws[tcl], nrb)
-
-        partials = jax.vmap(one_unit)(
-            tiles, tile_row, tile_col_local, recv, recv_src, recv_lane
-        )
-        return partials.sum(axis=0)
-
-    return jax.jit(run_selective) if jit else run_selective
+    return functools.partial(jax.jit(body) if jit else body, ops)
 
 
-def _make_simulate_overlap_fn(
-    plan: DevicePlan, op: OverlapPlan, *, jit: bool = False, transform=None
-) -> Callable[[jax.Array], jax.Array]:
-    """Overlapped vmap path: local tiles contract straight from the
-    owned x shard (no dependency on the emulated all_to_all), halo tiles
-    — one wave at a time — from the delivered per-wave workspaces: the
-    same dependency structure the shard_map step exposes to XLA's async
-    collectives. The wave count K is static (baked into the plan array
-    shapes), so the Python loop over waves unrolls at trace time."""
+def _simulate_overlap(plan: DevicePlan, op: OverlapPlan, transform):
+    """Overlapped vmap path as ``(ops, body)``: local tiles contract
+    straight from the owned x shard (no dependency on the emulated
+    all_to_all), halo tiles — one wave at a time — from the delivered
+    per-wave workspaces: the same dependency structure the shard_map
+    step exposes to XLA's async collectives. The wave count K is static
+    (baked into the plan array shapes), so the Python loop over waves
+    unrolls at trace time."""
     nrb = plan.num_row_blocks
-    sp = op.selective
     nw = op.waves
-    local_tiles = hoist_tiles(op.local_tiles, transform)
-    local_row = jnp.asarray(op.local_row)
-    local_slot = jnp.asarray(op.local_slot)
-    halo_tiles = hoist_tiles(op.halo_tiles, transform)  # [U, K, TH, bm, bn]
-    halo_row = jnp.asarray(op.halo_row)
-    halo_slot = jnp.asarray(op.halo_slot)
-    owned = jnp.asarray(sp.owned)  # [U, per]
-    wave_send_idx = jnp.asarray(op.wave_send_idx)  # [U, K, U, L]
-    wave_recv_src = jnp.asarray(op.wave_recv_src)  # [U, K, W]
-    wave_recv_lane = jnp.asarray(op.wave_recv_lane)
+    ops = (
+        hoist_tiles(op.local_tiles, transform),
+        jnp.asarray(op.local_row),
+        jnp.asarray(op.local_slot),
+        hoist_tiles(op.halo_tiles, transform),  # [U, K, TH, bm, bn]
+        jnp.asarray(op.halo_row),
+        jnp.asarray(op.halo_slot),
+        jnp.asarray(op.wave_recv_src),  # [U, K, W]
+        jnp.asarray(op.wave_recv_lane),
+        jnp.asarray(op.selective.owned),  # [U, per]
+        jnp.asarray(op.wave_send_idx),  # [U, K, U, L]
+    )
 
-    def run_overlap(xb: jax.Array) -> jax.Array:
+    def body(ops, xb: jax.Array) -> jax.Array:
+        *unit_ops, wave_recv_src, wave_recv_lane, owned, wave_send_idx = ops
         x_owned, recv = _emulated_wave_exchange(owned, wave_send_idx, xb)
 
         def one_unit(lt, lr, ls, ht, hr, hs, x_own_u, recv_u, src, lane):
@@ -315,20 +312,11 @@ def _make_simulate_overlap_fn(
             return y
 
         partials = jax.vmap(one_unit)(
-            local_tiles,
-            local_row,
-            local_slot,
-            halo_tiles,
-            halo_row,
-            halo_slot,
-            x_owned,
-            recv,
-            wave_recv_src,
-            wave_recv_lane,
+            *unit_ops, x_owned, recv, wave_recv_src, wave_recv_lane
         )
         return partials.sum(axis=0)
 
-    return jax.jit(run_overlap) if jit else run_overlap
+    return ops, body
 
 
 def pmvc_simulate(plan: DevicePlan, x: np.ndarray) -> np.ndarray:
@@ -359,12 +347,14 @@ def pmvc_simulate_overlap(
 def make_unit_mesh(num_units: int) -> Mesh:
     """Flat mesh over all local devices; the (node, core) structure of the
     plan is metadata — hierarchical collectives are an optimization knob."""
-    devs = np.asarray(jax.devices()[:num_units])
-    if devs.shape[0] != num_units:
+    found = jax.devices()
+    if len(found) < num_units:
         raise ValueError(
-            f"need {num_units} devices, have {len(jax.devices())} "
-            "(set XLA_FLAGS=--xla_force_host_platform_device_count=...)"
+            f"the shard_map executor runs one unit per device: need "
+            f"{num_units} devices, found {len(found)} on platform "
+            f"{found[0].platform!r}"
         )
+    devs = np.asarray(found[:num_units])
     return Mesh(devs, ("unit",))
 
 
@@ -444,7 +434,7 @@ def make_pmvc_step(
             return jax.lax.psum(y, "unit")
 
         return jax.jit(
-            _shard_map(
+            jax.shard_map(
                 step_overlap,
                 mesh=mesh,
                 in_specs=(P("unit"),) * 10,
@@ -460,7 +450,7 @@ def make_pmvc_step(
             return jax.lax.psum(y_part, "unit")
 
         return jax.jit(
-            _shard_map(
+            jax.shard_map(
                 step,
                 mesh=mesh,
                 in_specs=(P("unit"), P("unit"), P("unit"), P()),
@@ -476,7 +466,7 @@ def make_pmvc_step(
         return jax.lax.psum(y_part, "unit")
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             step_selective,
             mesh=mesh,
             in_specs=(

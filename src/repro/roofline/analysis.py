@@ -32,12 +32,9 @@ __all__ = [
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized across JAX versions: 0.4.x
-    returns a one-dict-per-device list, newer versions a flat dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``compiled.cost_analysis()``, with ``{}`` where the backend
+    reports nothing."""
+    return compiled.cost_analysis() or {}
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

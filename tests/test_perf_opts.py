@@ -55,10 +55,11 @@ def test_vocab_padding_transparent():
 
 
 def test_kv_fsdp_spec():
-    from repro.launch.mesh import make_abstract_mesh
+    from jax.sharding import AbstractMesh
+
     from repro.launch.shardings import param_spec
 
-    mesh = make_abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     cfg = get_arch("granite-20b")  # kv=1 — can't head-shard
     leaf = jax.ShapeDtypeStruct((52, 6144, 1, 128), jnp.bfloat16)
     base = param_spec("layers/attn/wk", leaf, cfg, mesh)

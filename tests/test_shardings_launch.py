@@ -6,16 +6,16 @@ production shape.
 """
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 
 from repro.config import SHAPES, get_arch, shape_applicable
 from repro.configs import ARCH_IDS
-from repro.launch.mesh import make_abstract_mesh
 from repro.launch.shardings import param_spec, tree_path_map
 from repro.launch.specs import abstract_params, input_specs
 from repro.models import build
 
-PROD_MESH = make_abstract_mesh((16, 16), ("data", "model"))
-POD_MESH = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+PROD_MESH = AbstractMesh((16, 16), ("data", "model"))
+POD_MESH = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_spec(path, leaf, cfg, mesh):
