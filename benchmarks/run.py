@@ -3,15 +3,13 @@
 Prints ``name,us_per_call,derived`` CSV blocks:
   1. Partition quality        (paper Tables 4.3–4.6 + Table 4.7 synthesis)
   2. PMVC phase decomposition (paper Figures 4.16–4.55), batch-swept
-  3. Kernel micro             (spBLAS level-2 analogue)
-  4. Roofline table           (§Roofline, from dry-run artifacts)
 
 Section 2 also writes ``BENCH_pmvc.json`` at the repo root (per-cell
 timings + phase costs) so the perf trajectory is tracked across PRs.
 """
 from pathlib import Path
 
-from benchmarks import bench_kernels, bench_partition, bench_pmvc, bench_roofline
+from benchmarks import bench_partition, bench_pmvc
 from repro.compile_cache import enable_compile_cache
 
 
@@ -28,12 +26,6 @@ def main() -> None:
 
     print("\n# === 2. PMVC phase decomposition (Figures 4.16-4.55) ===")
     bench_pmvc.run(json_path=str(Path(__file__).resolve().parent.parent / "BENCH_pmvc.json"))
-
-    print("\n# === 3. kernel micro ===")
-    bench_kernels.run()
-
-    print("\n# === 4. roofline table (from dry-run artifacts) ===")
-    bench_roofline.run()
 
 
 if __name__ == "__main__":

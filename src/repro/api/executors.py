@@ -29,11 +29,13 @@ from __future__ import annotations
 
 from typing import TYPE_CHECKING, Callable
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.api.registry import Registry
 from repro.pmvc.dist import (
-    hoist_tiles,
+    hoist_plan,
     make_pmvc_step,
     make_simulate_fn,
     make_unit_mesh,
@@ -43,6 +45,7 @@ from repro.pmvc.dist import (
 from repro.pmvc.plan_device import OverlapPlan
 from repro.sparse.bell import pad_x_blocks
 from repro.sparse.formats import csr_from_coo
+from repro.tracing import FETCH, PUT, span
 
 if TYPE_CHECKING:  # pragma: no cover - import cycle guard
     from repro.api.session import SparseSession
@@ -53,6 +56,25 @@ EXECUTORS = Registry("executor")
 register_executor = EXECUTORS.register
 
 SpmvFn = Callable[[np.ndarray], np.ndarray]
+
+
+def _put_blocks(dp, x: np.ndarray, owned_by=None) -> jax.Array:
+    """``x`` padded into column blocks (laid out by unit as the selective
+    plan ``owned_by`` says, if given) and copied to the device, in one
+    ``sparse.put`` span."""
+    with span(PUT) as s:
+        xb = pad_x_blocks(np.asarray(x, np.float32), dp.num_col_blocks, dp.bn)
+        if owned_by is not None:
+            xb = scatter_x_owned(owned_by, xb)
+        s.set_metadata(bytes=int(xb.nbytes))
+        return jnp.asarray(xb)
+
+
+def _fetch(y, n: int) -> np.ndarray:
+    """Wait for the product ``y`` and bring it back unblocked, in one
+    ``sparse.fetch`` span."""
+    with span(FETCH, bytes=int(y.nbytes)):
+        return unblock_y(jax.block_until_ready(y), n)
 
 
 @register_executor("reference")
@@ -84,8 +106,6 @@ def reference_executor(session: "SparseSession") -> SpmvFn:
 
 @register_executor("simulate")
 def simulate_executor(session: "SparseSession") -> SpmvFn:
-    import jax.numpy as jnp
-
     dp = session.device_plan
     run = make_simulate_fn(
         dp, session.selective, jit=True, transform=session.tile_transform
@@ -93,18 +113,13 @@ def simulate_executor(session: "SparseSession") -> SpmvFn:
     n = dp.shape[0]
 
     def spmv(x: np.ndarray) -> np.ndarray:
-        xb = jnp.asarray(
-            pad_x_blocks(np.asarray(x, np.float32), dp.num_col_blocks, dp.bn)
-        )
-        return unblock_y(run(xb), n)
+        return _fetch(run(_put_blocks(dp, x)), n)
 
     return spmv
 
 
 @register_executor("shard_map")
 def shard_map_executor(session: "SparseSession") -> SpmvFn:
-    import jax.numpy as jnp
-
     dp, sp = session.device_plan, session.selective
     mesh = make_unit_mesh(dp.num_units)
     step = make_pmvc_step(dp, mesh, selective=sp)
@@ -113,58 +128,43 @@ def shard_map_executor(session: "SparseSession") -> SpmvFn:
 
     if isinstance(sp, OverlapPlan):
         op = sp
-        local_tiles = hoist_tiles(op.local_tiles, tt)
-        local_row = jnp.asarray(op.local_row)
-        local_slot = jnp.asarray(op.local_slot)
-        halo_tiles = hoist_tiles(op.halo_tiles, tt)  # [U, K, TH, bm, bn]
-        halo_row = jnp.asarray(op.halo_row)
-        halo_slot = jnp.asarray(op.halo_slot)
-        wave_send_idx = jnp.asarray(op.wave_send_idx)
-        wave_recv_src = jnp.asarray(op.wave_recv_src)
-        wave_recv_lane = jnp.asarray(op.wave_recv_lane)
+        ops = hoist_plan(
+            (
+                op.local_tiles,
+                op.local_row,
+                op.local_slot,
+                op.halo_tiles,  # [U, K, TH, bm, bn]
+                op.halo_row,
+                op.halo_slot,
+                op.wave_send_idx,
+                op.wave_recv_src,
+                op.wave_recv_lane,
+            ),
+            tt,
+            tiles=(0, 3),
+        )
 
         def spmv_overlap(x: np.ndarray) -> np.ndarray:
-            xb = pad_x_blocks(np.asarray(x, np.float32), dp.num_col_blocks, dp.bn)
-            x_owned = jnp.asarray(scatter_x_owned(op.selective, xb))
-            y = step(
-                local_tiles,
-                local_row,
-                local_slot,
-                halo_tiles,
-                halo_row,
-                halo_slot,
-                x_owned,
-                wave_send_idx,
-                wave_recv_src,
-                wave_recv_lane,
-            )
-            return unblock_y(y, n)
+            # x_owned goes between the halo arrays and the wave schedule.
+            y = step(*ops[:6], _put_blocks(dp, x, op.selective), *ops[6:])
+            return _fetch(y, n)
 
         return spmv_overlap
 
-    tiles = hoist_tiles(dp.tiles, tt)
-    tile_row = jnp.asarray(dp.tile_row)
-
     if sp is None:
-        tile_col = jnp.asarray(dp.tile_col)
+        ops = hoist_plan((dp.tiles, dp.tile_row, dp.tile_col), tt)
 
         def spmv(x: np.ndarray) -> np.ndarray:
-            xb = jnp.asarray(
-                pad_x_blocks(np.asarray(x, np.float32), dp.num_col_blocks, dp.bn)
-            )
-            return unblock_y(step(tiles, tile_row, tile_col, xb), n)
+            return _fetch(step(*ops, _put_blocks(dp, x)), n)
 
         return spmv
 
-    tile_col_local = jnp.asarray(sp.tile_col_local)
-    send_idx = jnp.asarray(sp.send_idx)
-    recv_src = jnp.asarray(sp.recv_src)
-    recv_lane = jnp.asarray(sp.recv_lane)
+    ops = hoist_plan(
+        (dp.tiles, dp.tile_row, sp.tile_col_local, sp.send_idx, sp.recv_src, sp.recv_lane), tt
+    )
 
     def spmv_selective(x: np.ndarray) -> np.ndarray:
-        xb = pad_x_blocks(np.asarray(x, np.float32), dp.num_col_blocks, dp.bn)
-        x_owned = jnp.asarray(scatter_x_owned(sp, xb))
-        y = step(tiles, tile_row, tile_col_local, x_owned, send_idx, recv_src, recv_lane)
-        return unblock_y(y, n)
+        # x_owned goes between the tile arrays and the exchange schedule.
+        return _fetch(step(*ops[:3], _put_blocks(dp, x, sp), *ops[3:]), n)
 
     return spmv_selective

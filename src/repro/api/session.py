@@ -8,6 +8,8 @@ executor. See :mod:`repro.api` for the workflow overview.
 from __future__ import annotations
 
 import dataclasses
+import inspect
+import itertools
 from typing import Dict, Optional, Tuple, Union
 
 import numpy as np
@@ -28,6 +30,7 @@ from repro.pmvc.plan_device import (
 from repro.sparse.bell import x_block_owner
 from repro.sparse.delta import SparseDelta
 from repro.sparse.formats import COO
+from repro.tracing import SOLVE, span
 
 __all__ = ["SparseSession", "UpdateReport", "distribute"]
 
@@ -47,6 +50,25 @@ __all__ = ["SparseSession", "UpdateReport", "distribute"]
 PATCH_TOUCH_LIMIT = 0.25
 PATCH_DRIFT_LIMIT = 1.25
 REPLAN_FM_KW = {"fm_passes": 2, "fm_kicks": 1}
+
+# Numbers the solves of this process in turn: the ``solve`` argument of
+# their spans, by which a trace tells one request from the next.
+_SOLVES = itertools.count()
+
+
+def _solve_args(fn, kw: dict) -> dict:
+    """The ``sparse.solve`` span's ``iters`` (the budget: the solver's
+    default where the call gives none, 0 where it has none) and ``batch``
+    (right-hand sides)."""
+    iters = kw.get("iters")
+    if iters is None:
+        p = inspect.signature(fn).parameters.get("iters")
+        iters = 0 if p is None or p.default is p.empty else p.default
+    batch = kw.get("block", 1)
+    for key in ("b", "seeds"):
+        if kw.get(key) is not None and np.ndim(kw[key]) == 2:
+            batch = np.shape(kw[key])[0]
+    return {"iters": int(iters), "batch": int(batch)}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -284,7 +306,9 @@ class SparseSession:
         Solver results expose the iteration count as
         ``SolveResult.iters_run`` (``iters`` is the *budget* argument).
         """
-        return SOLVERS.get(solver)(self, **kw)
+        fn = SOLVERS.get(solver)
+        with span(SOLVE, solver=solver, solve=next(_SOLVES), **_solve_args(fn, kw)):
+            return fn(self, **kw)
 
     def batch_stepper(self, solver: str, slots: int, **config) -> BatchStepper:
         """Instantiate the slot-batched stepper for a registered

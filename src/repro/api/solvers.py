@@ -34,6 +34,7 @@ from typing import TYPE_CHECKING, Callable, List, Optional, Tuple
 import numpy as np
 
 from repro.api.registry import Registry
+from repro.tracing import FETCH, PUT, TRACE, UPDATE, span
 
 if TYPE_CHECKING:  # pragma: no cover - import cycle guard
     from repro.api.session import SparseSession
@@ -118,8 +119,17 @@ def _diag_of(session: "SparseSession") -> np.ndarray:
     return d
 
 
+def _to_device(*arrays: np.ndarray) -> tuple:
+    """A device loop's host vectors copied to the device, in one
+    ``sparse.put`` span."""
+    import jax.numpy as jnp
+
+    with span(PUT, bytes=sum(int(a.nbytes) for a in arrays)):
+        return tuple(jnp.asarray(a) for a in arrays)
+
+
 def _device_solver_loop(
-    iterate: Callable, carry0, iters: int, tol: float
+    iterate: Callable, carry0, iters: int, tol: float, solver: str
 ) -> Tuple[int, bool, np.ndarray, tuple]:
     """Run ``carry, res = iterate(carry)`` under ``lax.while_loop`` with
     tol early-stop, entirely on device.
@@ -127,7 +137,7 @@ def _device_solver_loop(
     Returns ``(iters_run, converged, residuals[:iters_run], carry)`` —
     the same early-stop semantics as the host loops (stop *after* the
     first iteration whose residual drops below ``tol``; ``tol=0`` runs
-    all ``iters``).
+    all ``iters``). The carry comes back as host arrays.
     """
     import jax
     import jax.numpy as jnp
@@ -151,9 +161,12 @@ def _device_solver_loop(
         jnp.zeros((max(iters, 1),), jnp.float32),
         carry0,
     )
-    k, done, res, carry = jax.lax.while_loop(cond, body, state0)
+    with span(TRACE, solver=solver):
+        state = jax.lax.while_loop(cond, body, state0)
+    with span(FETCH, bytes=sum(int(a.nbytes) for a in jax.tree.leaves(state))):
+        k, done, res, carry = jax.device_get(state)
     k = int(k)
-    return k, bool(done), np.asarray(res)[:k], carry
+    return k, bool(done), res[:k], carry
 
 
 def _result(
@@ -199,7 +212,11 @@ def power_iteration(
             return (x, lam), jnp.abs(lam - lam_prev)
 
         k, conv, res, (x, lam) = _device_solver_loop(
-            iterate, (jnp.asarray(x0), jnp.asarray(0.0, jnp.float32)), iters, tol
+            iterate,
+            (*_to_device(x0), jnp.asarray(0.0, jnp.float32)),
+            iters,
+            tol,
+            "power_iteration",
         )
         return _result("power_iteration", x, float(lam), res, k, conv)
 
@@ -209,8 +226,9 @@ def power_iteration(
     k = 0
     for k in range(1, iters + 1):  # noqa: B007 — k reported after the loop
         y = session.spmv(x)
-        lam = float(np.linalg.norm(y))
-        x = (y / max(lam, 1e-30)).astype(np.float32)
+        with span(UPDATE):
+            lam = float(np.linalg.norm(y))
+            x = (y / max(lam, 1e-30)).astype(np.float32)
         residuals.append(abs(lam - lam_prev))
         lam_prev = lam
         if tol and residuals[-1] < tol:
@@ -265,7 +283,11 @@ def block_power_iteration(
             return (q.T, lam), jnp.max(jnp.abs(lam - lam_prev))
 
         k, conv, res, (x, lam) = _device_solver_loop(
-            iterate, (jnp.asarray(x0), jnp.zeros((b,), jnp.float32)), iters, tol
+            iterate,
+            (*_to_device(x0), jnp.zeros((b,), jnp.float32)),
+            iters,
+            tol,
+            "block_power_iteration",
         )
         return _result(
             "block_power_iteration", x, float(np.max(np.asarray(lam))), res, k, conv
@@ -278,9 +300,10 @@ def block_power_iteration(
     k = 0
     for k in range(1, iters + 1):  # noqa: B007 — k reported after the loop
         y = session.spmv(x)  # [B, N] — one SpMM for the whole block
-        q, r = np.linalg.qr(y.T)
-        lam = np.abs(np.diagonal(r))
-        x = np.ascontiguousarray(q.T, dtype=np.float32)
+        with span(UPDATE):
+            q, r = np.linalg.qr(y.T)
+            lam = np.abs(np.diagonal(r))
+            x = np.ascontiguousarray(q.T, dtype=np.float32)
         residuals.append(float(np.max(np.abs(lam - lam_prev))))
         lam_prev = lam
         if tol and residuals[-1] < tol:
@@ -321,8 +344,7 @@ def jacobi(
         import jax.numpy as jnp
 
         mv = session.device_spmm()
-        bd = jnp.asarray(bv)
-        dd = jnp.asarray(d, jnp.float32)
+        bd, dd = _to_device(bv, d.astype(np.float32))
 
         def iterate(carry):
             z, r = carry  # r = b − Az carried forward: one SpMM per iter
@@ -333,7 +355,7 @@ def jacobi(
 
         z0 = jnp.zeros_like(bd)
         k, conv, res, (z, _) = _device_solver_loop(
-            iterate, (z0, bd - mv(z0)), iters, tol
+            iterate, (z0, bd - mv(z0)), iters, tol, "jacobi"
         )
         return _result(
             "jacobi", z, res[-1] if len(res) else 0.0, res, k, conv
@@ -344,9 +366,12 @@ def jacobi(
     residuals: List[float] = []
     k = 0
     for k in range(1, iters + 1):  # noqa: B007 — k reported after the loop
-        z = (z + r / d).astype(np.float32)
-        r = bv - session.spmv(z)
-        rn = np.linalg.norm(r, axis=-1)
+        with span(UPDATE):
+            z = (z + r / d).astype(np.float32)
+        y = session.spmv(z)
+        with span(UPDATE):
+            r = bv - y
+            rn = np.linalg.norm(r, axis=-1)
         residuals.append(float(rn.max() if batched else rn))
         if tol and residuals[-1] < tol:
             break
@@ -419,16 +444,15 @@ def pagerank(
         import jax.numpy as jnp
 
         mv = link.device_spmm()
-        sd = jnp.asarray(s)
         if normalize == "auto":
-            inv_d = jnp.asarray(inv_col)
-            dang_d = jnp.asarray(dangling)
+            sd, r0d, inv_d, dang_d = _to_device(s, r0, inv_col, dangling)
 
             def pr_step(r):
                 dmass = jnp.sum(r * dang_d, axis=-1, keepdims=True)
                 return mv(r * inv_d) + dmass * sd
 
         else:
+            sd, r0d = _to_device(s, r0)
             pr_step = mv
 
         def iterate(carry):
@@ -440,29 +464,26 @@ def pagerank(
             return (r_new,), (jnp.max(diff) if batched else diff)
 
         k, conv, res, (r,) = _device_solver_loop(
-            iterate, (jnp.asarray(r0),), iters, tol
+            iterate, (r0d,), iters, tol, "pagerank"
         )
         return _result(
             "pagerank", r, res[-1] if len(res) else 0.0, res, k, conv
         )
 
-    if normalize == "auto":
-
-        def pr_step(r):
-            dmass = (r * dangling).sum(axis=-1, keepdims=True)
-            return link.spmv(r * inv_col) + dmass * s
-
-    else:
-        pr_step = link.spmv
-
     r = r0
     residuals: List[float] = []
     k = 0
     for k in range(1, iters + 1):  # noqa: B007 — k reported after the loop
-        r_new = damping * pr_step(r) + (1.0 - damping) * s
-        norm = np.abs(r_new).sum(axis=-1, keepdims=True)
-        r_new = (r_new / np.maximum(norm, 1e-30)).astype(np.float32)
-        diff = np.abs(r_new - r).sum(axis=-1)
+        with span(UPDATE):
+            xs = r * inv_col if normalize == "auto" else r
+        y = link.spmv(xs)
+        with span(UPDATE):
+            if normalize == "auto":  # the dangling mass restarts at the teleport
+                y = y + (r * dangling).sum(axis=-1, keepdims=True) * s
+            r_new = damping * y + (1.0 - damping) * s
+            norm = np.abs(r_new).sum(axis=-1, keepdims=True)
+            r_new = (r_new / np.maximum(norm, 1e-30)).astype(np.float32)
+            diff = np.abs(r_new - r).sum(axis=-1)
         residuals.append(float(diff.max() if batched else diff))
         r = r_new
         if tol and residuals[-1] < tol:
@@ -497,20 +518,21 @@ def _cg_advance(session, z, r, p, rs):
     unaffected. Returns ``(z, r, p, rs, resid)`` with ``resid = √rs``
     per row (float64)."""
     ap = session.spmv(p)
-    denom = _row_dot(p, ap)
-    ok = np.abs(denom) >= 1e-30
-    alpha = np.where(ok, rs / np.where(ok, denom, 1.0), 0.0)
-    z_new = (z + alpha[:, None] * p).astype(np.float32)
-    r_new = (r - alpha[:, None] * ap).astype(np.float32)
-    rs_new = _row_dot(r_new, r_new)
-    beta = rs_new / np.maximum(rs, 1e-30)
-    p_new = (r_new + beta[:, None] * p).astype(np.float32)
-    sel = ok[:, None]
-    z = np.where(sel, z_new, z)
-    r = np.where(sel, r_new, r)
-    p = np.where(sel, p_new, p)
-    rs = np.where(ok, rs_new, rs)
-    return z, r, p, rs, np.sqrt(rs)
+    with span(UPDATE):
+        denom = _row_dot(p, ap)
+        ok = np.abs(denom) >= 1e-30
+        alpha = np.where(ok, rs / np.where(ok, denom, 1.0), 0.0)
+        z_new = (z + alpha[:, None] * p).astype(np.float32)
+        r_new = (r - alpha[:, None] * ap).astype(np.float32)
+        rs_new = _row_dot(r_new, r_new)
+        beta = rs_new / np.maximum(rs, 1e-30)
+        p_new = (r_new + beta[:, None] * p).astype(np.float32)
+        sel = ok[:, None]
+        z = np.where(sel, z_new, z)
+        r = np.where(sel, r_new, r)
+        p = np.where(sel, p_new, p)
+        rs = np.where(ok, rs_new, rs)
+        return z, r, p, rs, np.sqrt(rs)
 
 
 def _cg_batched(session, bv, iters, tol) -> SolveResult:
@@ -571,18 +593,19 @@ def conjugate_gradient(
     k = 0
     for k in range(1, iters + 1):  # noqa: B007 — k reported after the loop
         ap = session.spmv(p)
-        denom = float(p @ ap)
-        if abs(denom) < 1e-30:
-            break
-        alpha = rs / denom
-        z = (z + alpha * p).astype(np.float32)
-        r = (r - alpha * ap).astype(np.float32)
-        rs_new = float(r @ r)
-        residuals.append(float(np.sqrt(rs_new)))
-        if tol and residuals[-1] < tol:
-            break
-        p = (r + (rs_new / max(rs, 1e-30)) * p).astype(np.float32)
-        rs = rs_new
+        with span(UPDATE):
+            denom = float(p @ ap)
+            if abs(denom) < 1e-30:
+                break
+            alpha = rs / denom
+            z = (z + alpha * p).astype(np.float32)
+            r = (r - alpha * ap).astype(np.float32)
+            rs_new = float(r @ r)
+            residuals.append(float(np.sqrt(rs_new)))
+            if tol and residuals[-1] < tol:
+                break
+            p = (r + (rs_new / max(rs, 1e-30)) * p).astype(np.float32)
+            rs = rs_new
     return _result(
         "cg",
         z,

@@ -55,6 +55,7 @@ from repro.pmvc.plan_device import (
     SelectivePlan,
 )
 from repro.sparse.bell import pad_x_blocks
+from repro.tracing import HOIST, span
 
 __all__ = [
     "pmvc_simulate",
@@ -64,6 +65,7 @@ __all__ = [
     "make_pmvc_step",
     "make_unit_mesh",
     "hoist_tiles",
+    "hoist_plan",
     "phase_costs",
     "unblock_y",
     "pad_x",
@@ -107,6 +109,17 @@ def hoist_tiles(tiles: np.ndarray, transform=None) -> jax.Array:
     if dev is not None:
         return dev(jnp.asarray(tiles))
     return jnp.asarray(np.asarray(transform(np.asarray(tiles)), np.float32))
+
+
+def hoist_plan(arrays: tuple, transform=None, tiles=(0,)) -> tuple:
+    """Move a plan's arrays to the device, in one ``sparse.hoist`` span
+    that counts their bytes: those at the positions ``tiles`` through
+    :func:`hoist_tiles`, the others as they are."""
+    with span(HOIST, bytes=sum(int(a.nbytes) for a in arrays)):
+        return tuple(
+            hoist_tiles(a, transform) if i in tiles else jnp.asarray(a)
+            for i, a in enumerate(arrays)
+        )
 
 
 def pad_x(x: np.ndarray, ncb: int, bn: int) -> np.ndarray:
@@ -236,11 +249,7 @@ def make_simulate_fn(
     if isinstance(selective, OverlapPlan):
         ops, body = _simulate_overlap(plan, selective, transform)
     elif selective is None:
-        ops = (
-            hoist_tiles(plan.tiles, transform),
-            jnp.asarray(plan.tile_row),
-            jnp.asarray(plan.tile_col),
-        )
+        ops = hoist_plan((plan.tiles, plan.tile_row, plan.tile_col), transform)
 
         def body(ops, xb: jax.Array) -> jax.Array:
             def one_unit(t, r, c):
@@ -250,14 +259,17 @@ def make_simulate_fn(
 
     else:
         sp = selective
-        ops = (
-            hoist_tiles(plan.tiles, transform),
-            jnp.asarray(plan.tile_row),
-            jnp.asarray(sp.tile_col_local),
-            jnp.asarray(sp.recv_src),
-            jnp.asarray(sp.recv_lane),
-            jnp.asarray(sp.owned),  # [U, per]
-            jnp.asarray(sp.send_idx),  # [U, U, L]
+        ops = hoist_plan(
+            (
+                plan.tiles,
+                plan.tile_row,
+                sp.tile_col_local,
+                sp.recv_src,
+                sp.recv_lane,
+                sp.owned,  # [U, per]
+                sp.send_idx,  # [U, U, L]
+            ),
+            transform,
         )
 
         def body(ops, xb: jax.Array) -> jax.Array:
@@ -286,17 +298,21 @@ def _simulate_overlap(plan: DevicePlan, op: OverlapPlan, transform):
     unrolls at trace time."""
     nrb = plan.num_row_blocks
     nw = op.waves
-    ops = (
-        hoist_tiles(op.local_tiles, transform),
-        jnp.asarray(op.local_row),
-        jnp.asarray(op.local_slot),
-        hoist_tiles(op.halo_tiles, transform),  # [U, K, TH, bm, bn]
-        jnp.asarray(op.halo_row),
-        jnp.asarray(op.halo_slot),
-        jnp.asarray(op.wave_recv_src),  # [U, K, W]
-        jnp.asarray(op.wave_recv_lane),
-        jnp.asarray(op.selective.owned),  # [U, per]
-        jnp.asarray(op.wave_send_idx),  # [U, K, U, L]
+    ops = hoist_plan(
+        (
+            op.local_tiles,
+            op.local_row,
+            op.local_slot,
+            op.halo_tiles,  # [U, K, TH, bm, bn]
+            op.halo_row,
+            op.halo_slot,
+            op.wave_recv_src,  # [U, K, W]
+            op.wave_recv_lane,
+            op.selective.owned,  # [U, per]
+            op.wave_send_idx,  # [U, K, U, L]
+        ),
+        transform,
+        tiles=(0, 3),
     )
 
     def body(ops, xb: jax.Array) -> jax.Array:
