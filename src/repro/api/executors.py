@@ -37,7 +37,6 @@ from repro.api.registry import Registry
 from repro.pmvc.dist import (
     hoist_plan,
     make_pmvc_step,
-    make_simulate_fn,
     make_unit_mesh,
     scatter_x_owned,
     unblock_y,
@@ -107,13 +106,12 @@ def reference_executor(session: "SparseSession") -> SpmvFn:
 @register_executor("simulate")
 def simulate_executor(session: "SparseSession") -> SpmvFn:
     dp = session.device_plan
-    run = make_simulate_fn(
-        dp, session.selective, jit=True, transform=session.tile_transform
-    )
+    ops, body = session._hoisted()  # the tiles the device loops use too
+    run = jax.jit(body)
     n = dp.shape[0]
 
     def spmv(x: np.ndarray) -> np.ndarray:
-        return _fetch(run(_put_blocks(dp, x)), n)
+        return _fetch(run(ops, _put_blocks(dp, x)), n)
 
     return spmv
 

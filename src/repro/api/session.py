@@ -10,7 +10,7 @@ from __future__ import annotations
 import dataclasses
 import inspect
 import itertools
-from typing import Dict, Optional, Tuple, Union
+from typing import Dict, Hashable, Optional, Tuple, Union
 
 import numpy as np
 
@@ -19,7 +19,7 @@ from repro.api.executors import EXECUTORS, SpmvFn
 from repro.api.partitioners import PartitionResult, resolve_partitioner
 from repro.api.solvers import SOLVERS, STEPPERS, BatchStepper, SolveResult
 from repro.api.topology import Topology
-from repro.pmvc.dist import ExchangePlan, phase_costs
+from repro.pmvc.dist import ExchangePlan, make_simulate_fn, phase_costs
 from repro.pmvc.plan_device import (
     DevicePlan,
     OverlapPlan,
@@ -195,6 +195,9 @@ class SparseSession:
         self.executor = executor
         self.tile_transform = tile_transform
         self._spmv_cache: Dict[str, SpmvFn] = {}
+        # Device state built once per session: the hoisted plan, the
+        # device_spmm closure and the device loops' cond/body pairs.
+        self._device_cache: Dict[Hashable, object] = {}
 
     # -- lazy planning artifacts -------------------------------------------
     # Each property materializes a thunk in place on first access; the
@@ -271,6 +274,18 @@ class SparseSession:
             y = np.asarray(y, dtype=xa.dtype)
         return y
 
+    def _hoisted(self) -> tuple:
+        """The plan on the device as ``(ops, body)``
+        (:func:`repro.pmvc.dist.make_simulate_fn`), hoisted once per
+        session and shared by the ``simulate`` executor and
+        :meth:`device_spmm`, so a session keeps one copy of its tiles on
+        the device."""
+        if "hoist" not in self._device_cache:
+            self._device_cache["hoist"] = make_simulate_fn(
+                self.device_plan, self.selective, transform=self.tile_transform
+            )
+        return self._device_cache["hoist"]
+
     def device_spmm(self) -> "SpmvFn":
         """A pure-JAX ``x -> A @ x`` closure over device-resident plan
         arrays (``[N]`` or ``[B, N]``, same leading shape out).
@@ -279,26 +294,33 @@ class SparseSession:
         bodies, which is what the solvers' ``device_loop=True`` fast
         path does. Uses the vmap-over-units formulation (the ``simulate``
         executor's math) honoring the session's exchange strategy.
+
+        Built once per session and returned again on every call, over the
+        same hoisted plan as the ``simulate`` executor
+        (:meth:`_hoisted`); :meth:`with_executor` sessions share it.
+        Nothing invalidates it, since a session's plan never changes: a
+        changed matrix or value map is a new session (:meth:`update`,
+        :meth:`with_value_map`, :meth:`with_exchange`) with its own.
         """
-        import jax.numpy as jnp
+        if "spmm" not in self._device_cache:
+            import jax.numpy as jnp
 
-        from repro.pmvc.dist import make_simulate_fn
+            ops, body = self._hoisted()
+            dp = self.device_plan
+            n, m = dp.shape
+            ncb, bn = dp.num_col_blocks, dp.bn
 
-        dp = self.device_plan
-        run = make_simulate_fn(dp, self.selective, transform=self.tile_transform)
-        n, m = dp.shape
-        ncb, bn = dp.num_col_blocks, dp.bn
+            def mv(x):
+                squeeze = x.ndim == 1
+                x2 = x[None] if squeeze else x
+                b = x2.shape[0]
+                xp = jnp.zeros((b, ncb * bn), jnp.float32).at[:, :m].set(x2)
+                xb = jnp.moveaxis(xp.reshape(b, ncb, bn), 0, -1)
+                y = body(ops, xb).reshape(-1, b).T[:, :n]
+                return y[0] if squeeze else y
 
-        def mv(x):
-            squeeze = x.ndim == 1
-            x2 = x[None] if squeeze else x
-            b = x2.shape[0]
-            xp = jnp.zeros((b, ncb * bn), jnp.float32).at[:, :m].set(x2)
-            xb = jnp.moveaxis(xp.reshape(b, ncb, bn), 0, -1)
-            y = run(xb).reshape(-1, b).T[:, :n]
-            return y[0] if squeeze else y
-
-        return mv
+            self._device_cache["spmm"] = mv
+        return self._device_cache["spmm"]
 
     def solve(self, solver: str = "power_iteration", **kw) -> SolveResult:
         """Run a registered iterative solver (``iters=``, ``tol=``, ...).
@@ -477,6 +499,7 @@ class SparseSession:
             tile_transform=self.tile_transform,
         )
         sess._spmv_cache = self._spmv_cache  # share compiled closures
+        sess._device_cache = self._device_cache  # and the hoisted plan
         return sess
 
     def with_value_map(self, fn, *, materialize: bool = False) -> "SparseSession":

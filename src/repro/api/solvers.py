@@ -21,7 +21,11 @@ Two axes of scale on top of the basic drivers:
   ``power_iteration``, ``block_power_iteration``, ``pagerank``,
   ``jacobi``) runs the entire iteration under ``jax.lax.while_loop``
   via :meth:`SparseSession.device_spmm`, so steady-state solves never
-  bounce through the host between iterations.
+  bounce through the host between iterations. A session hoists its
+  plan once and keeps one loop program per solver and static
+  configuration (:func:`_device_solver_loop`): a later solve passes its
+  vectors (teleport, right-hand side, start) as operands and only
+  dispatches.
 
 Built-ins: ``"power_iteration"``, ``"block_power_iteration"``,
 ``"jacobi"``, ``"pagerank"``, ``"cg"``.
@@ -129,42 +133,72 @@ def _to_device(*arrays: np.ndarray) -> tuple:
 
 
 def _device_solver_loop(
-    iterate: Callable, carry0, iters: int, tol: float, solver: str
+    session: "SparseSession",
+    solver: str,
+    static: tuple,
+    iterate: Callable,
+    carry0: tuple,
+    operands: tuple,
+    iters: int,
+    tol: float,
 ) -> Tuple[int, bool, np.ndarray, tuple]:
-    """Run ``carry, res = iterate(carry)`` under ``lax.while_loop`` with
-    tol early-stop, entirely on device.
+    """Run ``carry, res = iterate(carry, operands)`` under
+    ``lax.while_loop`` with tol early-stop, entirely on device.
 
     Returns ``(iters_run, converged, residuals[:iters_run], carry)`` —
     the same early-stop semantics as the host loops (stop *after* the
     first iteration whose residual drops below ``tol``; ``tol=0`` runs
     all ``iters``). The carry comes back as host arrays.
+
+    The loop is built once per session and static configuration: the
+    ``cond``/``body`` pair is kept on the session under ``(solver,
+    iters, tol > 0, static, shapes and dtypes)``, so the next solve with
+    that key hits ``lax.while_loop``'s own trace and dispatch caches (no
+    re-trace, and the program keeps its name, ``jit_while``) and runs the
+    first solve's ``iterate``. So ``iterate`` may close over the
+    session's :meth:`~SparseSession.device_spmm` and the Python values
+    in ``static``, and nothing else: every per-solve array enters as an
+    operand, the evolving ``carry0``, the loop-invariant ``operands`` or
+    ``tol``. The ``sparse.trace`` span's ``cached`` says which.
     """
     import jax
     import jax.numpy as jnp
 
     check_tol = tol > 0.0  # static: baked into the traced body
+    key = (
+        "loop", solver, iters, check_tol, static,
+        tuple((a.shape, a.dtype) for a in (*carry0, *operands)),
+    )
+    loop = session._device_cache.get(key)
+    cached = loop is not None
+    if not cached:
 
-    def cond(state):
-        k, done = state[0], state[1]
-        return (k < iters) & jnp.logical_not(done)
+        def cond(state):
+            k, done = state[0], state[1]
+            return (k < iters) & jnp.logical_not(done)
 
-    def body(state):
-        k, _, res, carry = state
-        carry, r = iterate(carry)
-        res = res.at[k].set(r)
-        done = (r < tol) if check_tol else jnp.asarray(False)
-        return (k + 1, done, res, carry)
+        def body(state):
+            k, _, res, tol_, ops, carry = state
+            carry, r = iterate(carry, ops)
+            res = res.at[k].set(r)
+            done = (r < tol_) if check_tol else jnp.asarray(False)
+            return (k + 1, done, res, tol_, ops, carry)
+
+        loop = session._device_cache[key] = (cond, body)
 
     state0 = (
         jnp.asarray(0, jnp.int32),
         jnp.asarray(False),
         jnp.zeros((max(iters, 1),), jnp.float32),
+        jnp.asarray(tol, jnp.float32),
+        operands,
         carry0,
     )
-    with span(TRACE, solver=solver):
-        state = jax.lax.while_loop(cond, body, state0)
-    with span(FETCH, bytes=sum(int(a.nbytes) for a in jax.tree.leaves(state))):
-        k, done, res, carry = jax.device_get(state)
+    with span(TRACE, solver=solver, cached=cached):
+        k, done, res, _, _, carry = jax.lax.while_loop(*loop, state0)
+    out = (k, done, res, carry)
+    with span(FETCH, bytes=sum(int(a.nbytes) for a in jax.tree.leaves(out))):
+        k, done, res, carry = jax.device_get(out)
     k = int(k)
     return k, bool(done), res[:k], carry
 
@@ -204,7 +238,7 @@ def power_iteration(
 
         mv = session.device_spmm()
 
-        def iterate(carry):
+        def iterate(carry, _):
             x, lam_prev = carry
             y = mv(x)
             lam = jnp.linalg.norm(y)
@@ -212,11 +246,8 @@ def power_iteration(
             return (x, lam), jnp.abs(lam - lam_prev)
 
         k, conv, res, (x, lam) = _device_solver_loop(
-            iterate,
-            (*_to_device(x0), jnp.asarray(0.0, jnp.float32)),
-            iters,
-            tol,
-            "power_iteration",
+            session, "power_iteration", (), iterate,
+            _to_device(x0, np.zeros((), np.float32)), (), iters, tol,
         )
         return _result("power_iteration", x, float(lam), res, k, conv)
 
@@ -276,18 +307,15 @@ def block_power_iteration(
 
         mv = session.device_spmm()
 
-        def iterate(carry):
+        def iterate(carry, _):
             x, lam_prev = carry
             q, r = jnp.linalg.qr(mv(x).T)
             lam = jnp.abs(jnp.diagonal(r))
             return (q.T, lam), jnp.max(jnp.abs(lam - lam_prev))
 
         k, conv, res, (x, lam) = _device_solver_loop(
-            iterate,
-            (*_to_device(x0), jnp.zeros((b,), jnp.float32)),
-            iters,
-            tol,
-            "block_power_iteration",
+            session, "block_power_iteration", (), iterate,
+            _to_device(x0, np.zeros((b,), np.float32)), (), iters, tol,
         )
         return _result(
             "block_power_iteration", x, float(np.max(np.asarray(lam))), res, k, conv
@@ -346,16 +374,17 @@ def jacobi(
         mv = session.device_spmm()
         bd, dd = _to_device(bv, d.astype(np.float32))
 
-        def iterate(carry):
+        def iterate(carry, ops):
             z, r = carry  # r = b − Az carried forward: one SpMM per iter
-            z = z + r / dd
-            r = bd - mv(z)
+            b_, d_ = ops
+            z = z + r / d_
+            r = b_ - mv(z)
             rn = jnp.linalg.norm(r, axis=-1)
             return (z, r), (jnp.max(rn) if batched else rn)
 
         z0 = jnp.zeros_like(bd)
         k, conv, res, (z, _) = _device_solver_loop(
-            iterate, (z0, bd - mv(z0)), iters, tol, "jacobi"
+            session, "jacobi", (), iterate, (z0, bd - mv(z0)), (bd, dd), iters, tol
         )
         return _result(
             "jacobi", z, res[-1] if len(res) else 0.0, res, k, conv
@@ -444,27 +473,26 @@ def pagerank(
         import jax.numpy as jnp
 
         mv = link.device_spmm()
-        if normalize == "auto":
-            sd, r0d, inv_d, dang_d = _to_device(s, r0, inv_col, dangling)
 
-            def pr_step(r):
-                dmass = jnp.sum(r * dang_d, axis=-1, keepdims=True)
-                return mv(r * inv_d) + dmass * sd
-
-        else:
-            sd, r0d = _to_device(s, r0)
-            pr_step = mv
-
-        def iterate(carry):
+        def iterate(carry, ops):
             (r,) = carry
-            r_new = damping * pr_step(r) + (1.0 - damping) * sd
+            sd, *scale = ops
+            if scale:  # "auto": P·r = |A|·(D⁻¹r), dangling mass to the teleport
+                inv_d, dang_d = scale
+                dmass = jnp.sum(r * dang_d, axis=-1, keepdims=True)
+                y = mv(r * inv_d) + dmass * sd
+            else:
+                y = mv(r)
+            r_new = damping * y + (1.0 - damping) * sd
             norm = jnp.sum(jnp.abs(r_new), axis=-1, keepdims=True)
             r_new = r_new / jnp.maximum(norm, 1e-30)
             diff = jnp.sum(jnp.abs(r_new - r), axis=-1)
             return (r_new,), (jnp.max(diff) if batched else diff)
 
+        vecs = (s, inv_col, dangling) if normalize == "auto" else (s,)
+        r0d, *ops = _to_device(r0, *vecs)
         k, conv, res, (r,) = _device_solver_loop(
-            iterate, (r0d,), iters, tol, "pagerank"
+            link, "pagerank", (damping, normalize), iterate, (r0d,), tuple(ops), iters, tol
         )
         return _result(
             "pagerank", r, res[-1] if len(res) else 0.0, res, k, conv

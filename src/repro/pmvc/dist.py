@@ -33,15 +33,13 @@ al. 2012). :func:`phase_costs` carries the matching analytic model.
 Entry points: ``pmvc_simulate`` / ``pmvc_simulate_selective`` /
 ``pmvc_simulate_overlap`` (vmap over a stacked unit axis — CPU tests and
 the paper-reproduction benchmarks), ``make_simulate_fn`` (the same math
-as a reusable — optionally jitted — device closure over hoisted plan
-arrays; what the ``simulate`` executor and the device-resident solver
-loops build on), and ``make_pmvc_step`` (shard_map over a device mesh —
+as hoisted plan arrays and a pure body over them; what the ``simulate``
+executor and the device-resident solver loops build on), and ``make_pmvc_step`` (shard_map over a device mesh —
 the production path and dry-run).
 """
 from __future__ import annotations
 
-import functools
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -223,10 +221,10 @@ def make_simulate_fn(
     plan: DevicePlan,
     selective: ExchangePlan = None,
     *,
-    jit: bool = False,
     transform=None,
-) -> Callable[[jax.Array], jax.Array]:
-    """Build ``run(xb) -> y_blocks``, the vmap-over-units PMVC on padded
+) -> Tuple[tuple, Callable[[tuple, jax.Array], jax.Array]]:
+    """Hoist the plan to the device and return ``(ops, body)``:
+    ``body(ops, xb) -> y_blocks`` is the vmap-over-units PMVC on padded
     x blocks (``[NCB, bn]`` or ``[NCB, bn, B]`` → ``[NRB, bm(, B)]``).
 
     ``selective`` picks the exchange regime: ``None`` (replicated),
@@ -234,16 +232,19 @@ def make_simulate_fn(
     :class:`OverlapPlan` (pipelined local/halo — local tiles contract
     from the owned x shard, halo tiles from the delivered workspace).
 
-    Plan arrays are hoisted to device once, here — callers that keep the
-    closure (the ``simulate`` executor, the ``device_loop`` solver fast
-    path) never re-pay host→device conversion per call. They enter the
-    jitted program as arguments, never as closed-over constants: a
-    constant is compiled into the program, which at serving sizes
-    (GBs of tiles) costs minutes of compilation and tens of GiB of host
-    memory. The closure is pure JAX, so it can be jitted (``jit=True``)
-    and traced inside ``lax.fori_loop`` / ``while_loop`` solver bodies.
-    ``transform`` is the optional value-view map applied to tile
-    payloads at hoist time (see :func:`hoist_tiles`).
+    Each call hoists the plan arrays again (one ``sparse.hoist``):
+    :meth:`SparseSession._hoisted` makes the pair once per session and
+    both the ``simulate`` executor (``jax.jit(body)``, the ``jit_body``
+    program) and the ``device_loop`` solvers' :meth:`SparseSession.device_spmm`
+    run on it. The plan arrays enter a program as arguments, never as
+    closed-over constants: a constant is compiled into the program,
+    which at serving sizes (GBs of tiles) costs minutes of compilation
+    and tens of GiB of host memory. ``body`` is pure JAX, so it can be
+    jitted with ``ops`` as an argument, and traced inside
+    ``lax.while_loop`` solver bodies, which pass the closed-over ``ops``
+    to the loop program as operands. ``transform`` is the optional
+    value-view map applied to tile payloads at hoist time (see
+    :func:`hoist_tiles`).
     """
     nrb = plan.num_row_blocks
     if isinstance(selective, OverlapPlan):
@@ -285,7 +286,7 @@ def make_simulate_fn(
             )
             return partials.sum(axis=0)
 
-    return functools.partial(jax.jit(body) if jit else body, ops)
+    return ops, body
 
 
 def _simulate_overlap(plan: DevicePlan, op: OverlapPlan, transform):
@@ -335,11 +336,16 @@ def _simulate_overlap(plan: DevicePlan, op: OverlapPlan, transform):
     return ops, body
 
 
+def _simulate(plan: DevicePlan, selective: ExchangePlan, xb: jax.Array) -> jax.Array:
+    ops, body = make_simulate_fn(plan, selective)
+    return body(ops, xb)
+
+
 def pmvc_simulate(plan: DevicePlan, x: np.ndarray) -> np.ndarray:
     """vmap-over-units execution on a single host; ``x`` is ``[N]`` or a
     batch ``[B, N]``; returns y with the same leading shape."""
     xb = jnp.asarray(pad_x(np.asarray(x, np.float32), plan.num_col_blocks, plan.bn))
-    return unblock_y(make_simulate_fn(plan)(xb), plan.shape[0])
+    return unblock_y(_simulate(plan, None, xb), plan.shape[0])
 
 
 def pmvc_simulate_selective(
@@ -348,7 +354,7 @@ def pmvc_simulate_selective(
     """vmap execution of the *selective* exchange on a single host; one
     emulated all_to_all carries all B right-hand sides."""
     xb = jnp.asarray(pad_x(np.asarray(x, np.float32), plan.num_col_blocks, plan.bn))
-    return unblock_y(make_simulate_fn(plan, sp)(xb), plan.shape[0])
+    return unblock_y(_simulate(plan, sp, xb), plan.shape[0])
 
 
 def pmvc_simulate_overlap(
@@ -357,7 +363,7 @@ def pmvc_simulate_overlap(
     """vmap execution of the *overlapped* local/halo exchange on a single
     host — the oracle for the pipelined shard_map step (DESIGN.md §9)."""
     xb = jnp.asarray(pad_x(np.asarray(x, np.float32), plan.num_col_blocks, plan.bn))
-    return unblock_y(make_simulate_fn(plan, op)(xb), plan.shape[0])
+    return unblock_y(_simulate(plan, op, xb), plan.shape[0])
 
 
 def make_unit_mesh(num_units: int) -> Mesh:

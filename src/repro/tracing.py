@@ -22,11 +22,14 @@ SOLVE = "sparse.solve"
 ``batch`` (right-hand sides) and ``solve`` (a per-process sequence number)."""
 
 HOIST = "sparse.hoist"
-"""A plan's arrays moved to the device (tiles and index arrays); arg ``bytes``."""
+"""A plan's arrays moved to the device (tiles and index arrays), once per
+session and executor; arg ``bytes``."""
 
 TRACE = "sparse.trace"
 """The ``lax.while_loop`` call of a device loop: trace, lower, compile or
-load from the persistent cache, and dispatch; arg ``solver``."""
+load from the persistent cache, and dispatch; args ``solver`` and
+``cached``, true when the session's loop program was reused (dispatch
+only)."""
 
 PUT = "sparse.put"
 """Host vectors copied to the device: an executor's x, padded into column

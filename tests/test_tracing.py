@@ -98,11 +98,13 @@ def test_span_counts_and_bytes(recorded):
     expect = [tracing.HOIST, tracing.PUT, tracing.TRACE, tracing.FETCH]
     assert sorted(e[1] for e in pr) == sorted(expect)
     by_name = {e[1]: e[4] for e in pr}
-    assert by_name[tracing.HOIST]["bytes"] == plan_bytes  # the |A| view, hoisted again
+    # the |A| view is a session of its own: its first solve hoists its plan
+    assert by_name[tracing.HOIST]["bytes"] == plan_bytes
     n = sess.matrix.shape[1]
     # the teleport, the first ranks, the column scaling and the dangling mask
     assert by_name[tracing.PUT]["bytes"] == 4 * 4 * n
     assert by_name[tracing.TRACE]["solver"] == "pagerank"
+    assert by_name[tracing.TRACE]["cached"] == 0  # the view's first loop program
     # k, done, the residuals and the carried ranks
     assert by_name[tracing.FETCH]["bytes"] == 4 + 1 + 4 * ITERS + 4 * n
 
