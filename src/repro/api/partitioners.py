@@ -8,6 +8,13 @@ Built-in entries:
   :func:`repro.core.combined.two_level_partition`. Any other ``"XX-YY"``
   string over {N,H}×{L,C} (the [MeH12] combos, e.g. ``"NC-NC"``) is
   resolved on the fly.
+* ``"NL-HC:tiles"`` — the same NL-HC method on the plan's tile lines
+  (128-row block-rows, block-cols) instead of matrix lines, via
+  :func:`repro.core.combined.two_level_tile_partition`: every tile lies
+  on exactly one unit, so a unit stores only its share of the operator.
+  It takes the plan's tile shape as ``block=(bm, bn)``, which
+  :func:`repro.api.distribute` passes to any partitioner that declares
+  a ``block`` keyword.
 * ``"nezgt"`` / ``"hyper"`` — flat one-level partitions over all
   ``topology.units`` units (dim selectable via ``dim="rows"|"cols"``),
   for comparing against the two-level pipeline.
@@ -33,6 +40,7 @@ from repro.core.combined import (
     comm_stats,
     partition_lines,
     two_level_partition,
+    two_level_tile_partition,
 )
 from repro.core.metrics import fd, load_balance
 from repro.sparse.formats import COO
@@ -156,6 +164,39 @@ def _combo_partitioner(combo: str) -> Callable:
 
 for _combo in PAPER_COMBOS:
     PARTITIONERS.register(_combo, _combo_partitioner(_combo))
+
+
+# Balance of the tile partitioner's hypergraph level: a core may hold at
+# most this share above its node's mean tiles per core (the hypergraph
+# default, 0.10, would let one unit store a tenth more than its share).
+TILE_EPSILON = 0.02
+
+
+def _tile_partitioner(combo: str) -> Callable:
+    def run(
+        a: COO,
+        topology: Topology,
+        *,
+        block,
+        seed: int = 0,
+        timings: Optional[dict] = None,
+        fm_passes: Optional[int] = None,
+        fm_kicks: Optional[int] = None,
+        fm_screen_slack: Optional[int] = None,
+    ) -> PartitionResult:
+        bm, bn = (block, block) if isinstance(block, int) else block
+        fm_kw = {"epsilon": TILE_EPSILON, **(_fm_budget(fm_passes, fm_kicks, fm_screen_slack) or {})}
+        elem_unit = two_level_tile_partition(
+            a, topology.nodes, topology.cores, (bm, bn), combo,
+            seed=seed, timings=timings, fm_kw=fm_kw,
+        )
+        return PartitionResult(name=f"{combo}:tiles", topology=topology, elem_unit=elem_unit)
+
+    run.__name__ = f"partition_{combo.replace('-', '_')}_tiles"
+    return run
+
+
+PARTITIONERS.register("NL-HC:tiles", _tile_partitioner("NL-HC"))
 
 
 def _flat_partitioner(method: str) -> Callable:

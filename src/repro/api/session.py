@@ -15,8 +15,8 @@ from typing import Dict, Hashable, Optional, Tuple, Union
 import numpy as np
 
 from repro.api.exchange import resolve_exchange
-from repro.api.executors import EXECUTORS, SpmvFn
-from repro.api.partitioners import PartitionResult, resolve_partitioner
+from repro.api.executors import EXECUTORS, SpmvFn, default_executor
+from repro.api.partitioners import PARTITIONERS, PartitionResult, resolve_partitioner
 from repro.api.solvers import SOLVERS, STEPPERS, BatchStepper, SolveResult
 from repro.api.topology import Topology
 from repro.pmvc.dist import ExchangePlan, make_simulate_fn, phase_costs
@@ -796,7 +796,7 @@ class SparseSession:
         cfg = getattr(self, "_plan_config", None)
         if cfg is None:
             name = self.partition.name
-            if ":" in name:
+            if ":" in name and name not in PARTITIONERS:  # a flat "method:dim"
                 method, dim = name.split(":", 1)
                 cfg = {"combo": method, "seed": 0, "partitioner_kw": {"dim": dim}}
             else:
@@ -855,7 +855,7 @@ def distribute(
     topology: Topology,
     combo: str = "NL-HL",
     exchange: str = "selective",
-    executor: str = "simulate",
+    executor: Optional[str] = None,
     block: Union[int, Tuple[int, int]] = 16,
     seed: int = 0,
     cache_dir: Optional[str] = None,
@@ -877,6 +877,11 @@ def distribute(
     DESIGN.md §9) or ``"overlap:K"`` (the halo split into K prioritized
     waves, wave k's contraction hiding wave k+1's transfer — DESIGN.md
     §13).
+
+    ``executor`` names the session's default executor; ``None`` takes
+    :func:`repro.api.executors.default_executor`: ``"shard_map"`` where
+    JAX has an accelerator device for each of several units, else
+    ``"simulate"``.
 
     ``locality_weight`` (a partitioner kwarg, forwarded) biases the
     partition toward keeping tiles on the unit that owns their x
@@ -911,6 +916,8 @@ def distribute(
     check, not a planning input.
     """
     bm, bn = (block, block) if isinstance(block, int) else block
+    if executor is None:
+        executor = default_executor(topology.units)
     kw = dict(partitioner_kw)
     lw = kw.pop("locality_weight", None)
     if lw is None:
@@ -953,7 +960,7 @@ def distribute(
         if float(lw) != 0.0:
             kw["locality_weight"] = float(lw)
             kw.setdefault("locality_bn", bn)
-        part = resolve_partitioner(combo)(a, topology, seed=seed, **kw)
+        part = _partition(resolve_partitioner(combo), a, topology, seed, (bm, bn), kw)
         dp = pack_units(a, part.elem_unit, topology.units, bm, bn)
         sp = resolve_exchange(exchange)(dp)
     sess = SparseSession(
@@ -969,6 +976,14 @@ def distribute(
     if validate is not None:
         sess.verify(level=validate)
     return sess
+
+
+def _partition(run, a, topology, seed, block, kw) -> PartitionResult:
+    """Run a partitioner, passing the plan's tile shape as ``block`` to
+    one that declares that keyword (a partitioner of tile lines)."""
+    if "block" in inspect.signature(run).parameters:
+        kw = {**kw, "block": block}
+    return run(a, topology, seed=seed, **kw)
 
 
 # Candidate locality weights the overlap auto-tuner plans at — 0.0 (the
@@ -1013,7 +1028,7 @@ def _auto_locality_plan(a, topology, combo, exchange, bm, bn, seed, base_kw):
         if w != 0.0:
             kw["locality_weight"] = w
             kw.setdefault("locality_bn", bn)
-        part = run(a, topology, seed=seed, **kw)
+        part = _partition(run, a, topology, seed, (bm, bn), kw)
         dp = pack_units(a, part.elem_unit, topology.units, bm, bn)
         sp = make_exchange(dp)
         return part, dp, sp

@@ -34,6 +34,7 @@ __all__ = [
     "TwoLevelPlan",
     "comm_stats",
     "two_level_partition",
+    "two_level_tile_partition",
     "partition_lines",
 ]
 
@@ -331,3 +332,52 @@ def two_level_partition(
         inter_fd=inter_fd,
         hyper_cut=hyper_cut,
     )
+
+
+def two_level_tile_partition(
+    a: COO,
+    f: int,
+    c: int,
+    block: Tuple[int, int],
+    combo: str = "NL-HC",
+    *,
+    seed: int = 0,
+    timings: Optional[Dict[str, float]] = None,
+    fm_kw: Optional[Dict[str, int]] = None,
+) -> np.ndarray:
+    """The two-level method on the lines of ``a``'s tile grid, not on its
+    matrix lines: returns the unit (``node * c + core``) of every element.
+
+    The grid has one entry per ``block``-sized tile that holds a non-zero
+    (block-rows × block-cols). :func:`two_level_partition` splits it as
+    ``combo`` says — for ``"NL-HC"``, NEZGT over block-rows across the
+    ``f`` nodes, then per node a hypergraph over its block-cols, its
+    block-rows as nets, across the ``c`` cores — with every line weighted
+    by its tile count, so the units balance the tiles they store. Each
+    element follows its tile, so every tile lies on exactly one unit; the
+    partitioners see one vertex per block line instead of one per matrix
+    line.
+    """
+    bm, bn = block
+    nrb, ncb = -(-a.shape[0] // bm), -(-a.shape[1] // bn)
+    rb, cb = a.row // bm, a.col // bn
+    keys = np.unique(rb.astype(np.int64) * ncb + cb)  # the tiles that hold an element
+    grid = COO(
+        (nrb, ncb),
+        (keys // ncb).astype(np.int32),
+        (keys % ncb).astype(np.int32),
+        np.ones(keys.shape[0], dtype=np.float32),
+    )
+    plan = two_level_partition(grid, f, c, combo, seed=seed, timings=timings, fm_kw=fm_kw)
+    # A level partitions whole lines: every tile of an inter-level line
+    # lies on one node, and every tile of an intra-level line of a node on
+    # one core. So a line's node and a (node, line)'s core are lookups.
+    lines = {"rows": (grid.row, rb, nrb), "cols": (grid.col, cb, ncb)}  # of tiles, of elements
+    inter_t, inter_e, n_inter = lines[plan.inter.dim]
+    intra_t, intra_e, n_intra = lines[plan.intra.dim]
+    node_of_line = np.zeros(n_inter, np.int32)
+    node_of_line[inter_t] = plan.elem_node
+    core_of = np.zeros((f, n_intra), np.int32)
+    core_of[plan.elem_node, intra_t] = plan.elem_core
+    elem_node = node_of_line[inter_e]
+    return elem_node.astype(np.int64) * c + core_of[elem_node, intra_e]

@@ -39,6 +39,7 @@ the production path and dry-run).
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -95,27 +96,42 @@ _DEVICE_UFUNC = {np.absolute: jnp.abs, abs: jnp.abs, np.sign: jnp.sign,
                  np.negative: jnp.negative, np.square: jnp.square}
 
 
-def hoist_tiles(tiles: np.ndarray, transform=None) -> jax.Array:
-    """Move a tile payload to device, applying an optional elementwise
-    value transform (a :meth:`SparseSession.with_value_map` view): known
-    ufuncs run on device after the transfer, anything else is applied to
-    the host array on the way in (one transient host copy, never a
-    persistent one)."""
+def hoist_tiles(tiles: np.ndarray, transform=None, put=jnp.asarray) -> jax.Array:
+    """Move a tile payload to device with ``put``, applying an optional
+    elementwise value transform (a :meth:`SparseSession.with_value_map`
+    view): known ufuncs run on device after the transfer, anything else
+    is applied to the host array on the way in (one transient host copy,
+    never a persistent one)."""
     if transform is None:
-        return jnp.asarray(tiles)
+        return put(tiles)
     dev = _DEVICE_UFUNC.get(transform)
     if dev is not None:
-        return dev(jnp.asarray(tiles))
-    return jnp.asarray(np.asarray(transform(np.asarray(tiles)), np.float32))
+        return dev(put(tiles))
+    return put(np.asarray(transform(np.asarray(tiles)), np.float32))
 
 
-def hoist_plan(arrays: tuple, transform=None, tiles=(0,)) -> tuple:
-    """Move a plan's arrays to the device, in one ``sparse.hoist`` span
-    that counts their bytes: those at the positions ``tiles`` through
-    :func:`hoist_tiles`, the others as they are."""
-    with span(HOIST, bytes=sum(int(a.nbytes) for a in arrays)):
+def hoist_plan(arrays: tuple, transform=None, tiles=(0,), sharding=None) -> tuple:
+    """Move a plan's arrays (each with a leading unit axis) to the device,
+    in one ``sparse.hoist`` span: those at the positions ``tiles`` through
+    :func:`hoist_tiles`, the others as they are.
+
+    Without ``sharding`` every array lands whole on the default device.
+    With a ``NamedSharding`` over the unit mesh (``P("unit")``), each
+    unit's slice goes from the host straight to its own device and no
+    device ever holds another unit's share. The span counts ``bytes``
+    (all arrays), ``units`` (their leading axis) and ``bytes_per_device``
+    (what the fullest device receives)."""
+    total = sum(int(a.nbytes) for a in arrays)
+    if sharding is None:
+        put, per_device = jnp.asarray, total
+    else:
+        put = functools.partial(jax.device_put, device=sharding)
+        per_device = sum(
+            int(np.prod(sharding.shard_shape(a.shape))) * a.itemsize for a in arrays
+        )
+    with span(HOIST, bytes=total, units=int(arrays[0].shape[0]), bytes_per_device=per_device):
         return tuple(
-            hoist_tiles(a, transform) if i in tiles else jnp.asarray(a)
+            hoist_tiles(a, transform, put) if i in tiles else put(a)
             for i, a in enumerate(arrays)
         )
 

@@ -413,17 +413,20 @@ def pack_units(
     t_unit, t_rb, t_cb, tile_of_elem = _tile_index(
         elem_unit, a.row // bm, a.col // bn, num_units, nrb, ncb
     )
-    num_tiles = t_unit.shape[0]
-    all_tiles = np.zeros((num_tiles, bm, bn), dtype=np.float32)
-    all_tiles[tile_of_elem, a.row % bm, a.col % bn] = a.val.astype(np.float32)
-
     counts = np.bincount(t_unit, minlength=num_units)
     t_max = max(int(counts.max(initial=0)), 1)
     # `uniq` is ascending, i.e. (unit, block-row, block-col)-ordered: each
     # unit's tiles already sit consecutively in the stable by-row order
-    # the old per-unit argsort produced, so one ragged scatter replaces
-    # the Python loop over units (bit-identical output).
-    tiles = stack_ragged(all_tiles, counts, t_max)
+    # the old per-unit argsort produced. A tile's place in the stacked
+    # [U, T] layout is its unit's row at its rank within the unit, so the
+    # elements scatter straight into the zero-padded stack: the host holds
+    # the payload once, not as a flat copy and then a stacked one.
+    start = np.zeros(num_units + 1, dtype=np.int64)
+    np.cumsum(counts, out=start[1:])
+    place = t_unit * t_max + (np.arange(t_unit.shape[0], dtype=np.int64) - start[t_unit])
+    tiles = np.zeros((num_units, t_max, bm, bn), dtype=np.float32)
+    flat = (place[tile_of_elem] * bm + a.row % bm) * bn + a.col % bn
+    tiles.reshape(-1)[flat] = a.val.astype(np.float32)
     tile_row = stack_ragged(t_rb, counts, t_max)
     tile_col = stack_ragged(t_cb, counts, t_max)
     return DevicePlan(

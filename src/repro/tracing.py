@@ -23,7 +23,10 @@ SOLVE = "sparse.solve"
 
 HOIST = "sparse.hoist"
 """A plan's arrays moved to the device (tiles and index arrays), once per
-session and executor; arg ``bytes``."""
+session and executor; args ``bytes`` (all of them), ``units`` and
+``bytes_per_device`` (what the fullest device receives: all of it on one
+device, a unit's share where the ``shard_map`` executor places each
+unit's arrays on its own device)."""
 
 TRACE = "sparse.trace"
 """The ``lax.while_loop`` call of a device loop: trace, lower, compile or
@@ -33,7 +36,10 @@ only)."""
 
 PUT = "sparse.put"
 """Host vectors copied to the device: an executor's x, padded into column
-blocks, or a device loop's operands; arg ``bytes``."""
+blocks, or a device loop's operands; arg ``bytes``. On the ``shard_map``
+path also ``halo_bytes``, the x blocks the product's exchange moves
+between units (from the plan), and ``reduce_bytes``, the partial y that
+its ``psum`` sums."""
 
 FETCH = "sparse.fetch"
 """The host waits for a device result and copies it back; arg ``bytes``."""

@@ -7,6 +7,8 @@ shapes are those of the chip smoke run's plan — a banded 60k x 60k /
 1.2M-nnz operator on ``Topology(4, 4)`` at 128 x 128 tiles — written as
 constants so no test plans at that size.
 """
+import types
+
 import numpy as np
 import pytest
 
@@ -17,6 +19,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from repro.api import Topology, distribute
 from repro.kernels.spmv import bell_spmm
 from repro.pmvc.dist import _unit_spmm, make_pmvc_step
+from repro.pmvc.plan_device import SelectivePlan
 from repro.sparse.generate import banded_coo
 
 # The smoke plan: 16 units x 1382 padded tiles, 469 block rows/cols.
@@ -105,6 +108,36 @@ def test_selective_step_compiles_on_four_chips(topo):
     text = compiled.as_text()
     assert "all-to-all" in text
     assert "all-reduce" in text
+
+
+def test_selective_step_fits_each_chip_at_the_four_chip_hpcg_size(topo):
+    """The step of the four-chip HPCG cell (``hpcg-4x104``: a 208 x 208 x
+    104 stencil, 35,152 block rows and cols of 128) with each unit at the
+    most tiles its plan may store (1.05 x a quarter of the 522,040
+    distinct tiles) and its exchange at the widest it can be (every
+    owned block on every route, every block in the workspace): what one
+    chip holds of it fits in 9.4 GB, a quarter of the operator and not
+    the whole."""
+    units, tiles, nb = 4, 137036, 35152
+    per = nb // units
+    dp = types.SimpleNamespace(num_row_blocks=nb)
+    sp = SelectivePlan(units, per, per, *(None,) * 6, 0, 0)
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("unit",))
+    unit = NamedSharding(mesh, P("unit"))
+    shapes = [
+        ((units, tiles, BLOCK, BLOCK), jnp.float32),
+        ((units, tiles), jnp.int32),
+        ((units, tiles), jnp.int32),
+        ((units, per, BLOCK), jnp.float32),
+        ((units, units, per), jnp.int32),
+        ((units, nb), jnp.int32),
+        ((units, nb), jnp.int32),
+    ]
+    step = make_pmvc_step(dp, mesh, selective=sp)
+    compiled = step.lower(*(_sds(s, d, unit) for s, d in shapes)).compile()
+    m = compiled.memory_analysis()
+    per_chip = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes
+    assert tiles * BLOCK * BLOCK * 4 < per_chip < 9.4e9
 
 
 @pytest.mark.parametrize("batch", [1, 8, 64])
